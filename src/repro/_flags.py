@@ -18,15 +18,24 @@ def force_host_device_count(n: int, current: str | None = None) -> str:
     return f"{cur} --xla_force_host_platform_device_count={n}".strip()
 
 
+# Platform of every child process started from `subprocess_env`.  Such
+# children are CPU ranks by design (forced host devices, gloo collectives);
+# pinning them keeps them off an accelerator their parent may hold — a
+# chip belongs to one process at a time.
+CHILD_PLATFORM = "cpu"
+
+
 def subprocess_env(n_devices: int, src_path: str) -> dict:
-    """Environment for a fresh-interpreter jax subprocess: `n_devices`
-    forced host devices (overriding any ambient forced count) and
-    `src_path` prepended to PYTHONPATH so `repro` imports uninstalled.
+    """Environment for a fresh-interpreter jax subprocess: the CPU
+    platform, `n_devices` forced host devices (overriding any ambient
+    forced count) and `src_path` prepended to PYTHONPATH so `repro`
+    imports uninstalled.
 
     Shared by tests/_mp_helpers.py, repro.bench.subproc and
     repro.cluster.local so their subprocess environments cannot drift
     apart."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = CHILD_PLATFORM
     env["XLA_FLAGS"] = force_host_device_count(
         n_devices, env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = src_path + os.pathsep + env.get("PYTHONPATH", "")

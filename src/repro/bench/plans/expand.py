@@ -53,9 +53,12 @@ PHYSICS_FIELDS = ("grid", "profile", "stim", "seed", "neurons_per_column",
 
 def runtime_env() -> dict:
     """The code-relevant environment folded into cell hashes: jax version
-    + backend decide numerics and HLO, so a bump re-runs every cell."""
+    + backend decide numerics and HLO, so a bump re-runs every cell.  The
+    backend is the one the cells' child processes run on, not this
+    process's: asking jax for it here would claim this process's device."""
     import jax
-    return dict(jax=jax.__version__, backend=jax.default_backend())
+    from ..._flags import CHILD_PLATFORM
+    return dict(jax=jax.__version__, backend=CHILD_PLATFORM)
 
 
 def cell_key(cell: dict) -> str:

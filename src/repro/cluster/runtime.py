@@ -62,15 +62,8 @@ def ensure_initialized(cfg: Optional[ClusterConfig] = None) -> bool:
     cfg = cfg or from_env()
     if cfg is None:
         return False
-    # CPU collectives for cross-process ppermute/all_gather.  The value
-    # comes from JAX_CPU_COLLECTIVES_IMPLEMENTATION (an explicit operator
-    # choice, e.g. "mpi", wins over the gloo default) but must be applied
-    # via config.update — jax 0.4.37 does not read this env var itself.
-    impl = os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except (AttributeError, LookupError):
-        pass
+    # CPU collectives for cross-process ppermute/all_gather come from
+    # JAX_CPU_COLLECTIVES_IMPLEMENTATION, which `cluster_env` sets
     jax.distributed.initialize(coordinator_address=cfg.coordinator,
                                num_processes=cfg.num_processes,
                                process_id=cfg.process_id)

@@ -14,8 +14,9 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
-INVALID = jnp.int32(2 ** 31 - 1)
+INVALID = np.int32(2 ** 31 - 1)   # host constant: importing claims no device
 
 
 def compact_indices(mask: jnp.ndarray, cap: int, fill: int

@@ -392,9 +392,10 @@ def _src_false(planT):
 
 def make_run_program(spec: SimSpec, plan: ShardPlan, mesh: Mesh,
                      eplan=None, caps: Optional[dict] = None,
-                     hier_groups=None, splan=None):
-    """Returns run(state, t0, n_steps) -> (state, raster, timings), executing
-    one shard per device of the `cells` mesh axis.  (Constructed via
+                     hier_groups=None, splan=None) -> "RunProgram":
+    """Returns the RunProgram whose run(state, t0, n_steps) -> (state,
+    raster, timings) executes one shard per device of the `cells` mesh
+    axis.  (Constructed via
     `core.StepProgram`; this is the machinery behind its `.run` handle.)
 
     `plan` must be HOST-addressable (the stacked tree `build` returns):
@@ -463,13 +464,24 @@ def make_run_program(spec: SimSpec, plan: ShardPlan, mesh: Mesh,
         out_specs=(state_specs, P(None, *pspec),
                    jax.tree.map(lambda s: P(None, *s), tm_specs))))
 
-    def runner(state, t0: int, n_steps: int):
-        ts = dist_sharding.replicated_put(
+    def times(t0: int, n_steps: int):
+        return dist_sharding.replicated_put(
             mesh, jnp.arange(t0, t0 + n_steps, dtype=jnp.int32))
-        state, raster, tm = run(plan_d, state, ts)
-        return state, raster, tm
 
-    return runner
+    return RunProgram(
+        run=lambda state, t0, n_steps: run(plan_d, state,
+                                           times(t0, n_steps)),
+        lower=lambda state, t0, n_steps: run.lower(plan_d, state,
+                                                   times(t0, n_steps)))
+
+
+class RunProgram(NamedTuple):
+    """`run(state, t0, n_steps) -> (state, raster[T, H, N], timings)` and
+    `lower(state, t0, n_steps)`, the `jax.stages.Lowered` of the program
+    `run` executes for those arguments (for its compiled text and memory
+    analysis)."""
+    run: Callable
+    lower: Callable
 
 
 class PhasePrograms(NamedTuple):
@@ -576,7 +588,7 @@ def make_sharded_run(spec: SimSpec, plan: ShardPlan, mesh: Mesh,
                      eplan=None, caps: Optional[dict] = None):
     """Deprecated alias of the `StepProgram.run` machinery."""
     _warn_deprecated("make_sharded_run")
-    return make_run_program(spec, plan, mesh, eplan=eplan, caps=caps)
+    return make_run_program(spec, plan, mesh, eplan=eplan, caps=caps).run
 
 
 def make_phase_fns(spec: SimSpec, plan: ShardPlan, mesh: Mesh,

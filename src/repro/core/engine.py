@@ -32,7 +32,9 @@ from . import connectivity, stimulus, topology
 from .params import (DEFAULT_IZH, DEFAULT_STDP, EngineConfig, GridConfig,
                      IzhikevichParams, StdpParams)
 
-NEG_TIME = jnp.float32(-1.0e9)   # "never" sentinel for last-spike times
+# "never" sentinel for last-spike times; a host constant, so importing
+# this module claims no device
+NEG_TIME = np.float32(-1.0e9)
 
 
 class ShardPlan(NamedTuple):
@@ -130,33 +132,39 @@ def build(cfg: GridConfig, eng: EngineConfig,
             columns=_owned_columns_padded(cfg, eng, h, c_cap),
             shard_id=np.int32(h)))
 
-    stacked = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *plans)
+    # host arrays: placement (StepProgram.place) puts each shard straight
+    # on its device, so no device ever holds the whole network on the way
+    stacked = jax.tree.map(lambda *xs: np.stack(xs), *plans)
     spec = SimSpec(cfg=cfg, eng=eng, izh=izh, stdp=stdp, n_local=n_cap,
                    e_cap=e_cap, s_cap=s_cap, n_total=cfg.n_neurons)
 
-    w0 = jnp.asarray(np.stack([t.weight0 for t in tables]))
+    w0 = np.stack([t.weight0 for t in tables])
     state = init_state(spec, stacked)._replace(w=w0)
     return spec, stacked, state
 
 
+def init_neurons(spec: SimSpec, exc_mask: np.ndarray):
+    """Host (v, u, last_post) at rest for a stacked [H, N] `exc_mask`."""
+    izh = spec.izh
+    v = np.full(exc_mask.shape, izh.v_init, np.float32)
+    b = np.where(exc_mask, np.float32(izh.b_exc), np.float32(izh.b_inh))
+    return v, b * v, np.full(exc_mask.shape, NEG_TIME)
+
+
 def init_state(spec: SimSpec, plan: ShardPlan) -> ShardState:
-    """Fresh dynamic state (zero weights; `build` installs w0) [H, ...]."""
+    """Fresh dynamic state (zero weights; `build` installs w0) [H, ...],
+    as host arrays."""
     if spec.stream is not None:
         from . import stream_engine
         return stream_engine.init_state(spec, plan)
-
-    def one(p: ShardPlan) -> ShardState:
-        v = jnp.full(p.exc_mask.shape, spec.izh.v_init, jnp.float32)
-        b = jnp.where(p.exc_mask, spec.izh.b_exc, spec.izh.b_inh)
-        return ShardState(
-            v=v, u=b.astype(jnp.float32) * v,
-            last_post=jnp.full(p.exc_mask.shape, NEG_TIME),
-            w=jnp.zeros(p.syn_valid.shape, jnp.float32),
-            last_arr=jnp.full(p.syn_valid.shape, NEG_TIME),
-            arr_ring=jnp.zeros(
-                (spec.cfg.n_delay_slots,) + p.syn_valid.shape, bool))
-
-    return jax.vmap(one)(plan)
+    v, u, last_post = init_neurons(spec, np.asarray(plan.exc_mask))
+    e_shape = np.shape(plan.syn_valid)               # [H, E]
+    return ShardState(
+        v=v, u=u, last_post=last_post,
+        w=np.zeros(e_shape, np.float32),
+        last_arr=np.full(e_shape, NEG_TIME),
+        arr_ring=np.zeros((e_shape[0], spec.cfg.n_delay_slots)
+                          + e_shape[1:], bool))
 
 
 # ----------------------------------------------------------------------------
@@ -370,6 +378,6 @@ def run(spec: SimSpec, plan: ShardPlan, state: ShardState, t0: int,
         s, out = step(s, t)
         return s, out
 
-    ts = jnp.arange(t0, t0 + n_steps, dtype=jnp.int32)
+    ts = t0 + jnp.arange(n_steps, dtype=jnp.int32)   # t0 may be traced
     state, (raster, tm) = jax.lax.scan(body, state, ts)
     return state, raster, tm

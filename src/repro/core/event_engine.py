@@ -115,8 +115,7 @@ def build_event_plan(spec: SimSpec, cap_ev_factor: float = 0.25,
     for fwd, inr in zip(groups_fwd, groups_in):
         fwd_all.append(_pad_rows(fwd, S, kf_max))
         in_all.append(_pad_rows(inr, N, ki_max))
-    plan = EventPlan(fwd_rows=jnp.asarray(np.stack(fwd_all)),
-                     in_rows=jnp.asarray(np.stack(in_all)))
+    plan = EventPlan(fwd_rows=np.stack(fwd_all), in_rows=np.stack(in_all))
     cap_ev = int(spec.e_cap * cap_ev_factor)
     cap_ev = max(256, -(-cap_ev // 128) * 128)
     return plan, cap_ev
@@ -128,9 +127,9 @@ def init_event_state(spec: SimSpec, base: ShardState, cap_ev: int
     D = spec.cfg.n_delay_slots
     return EventState(
         base=base,
-        ev_ring=jnp.full((H, D, cap_ev), -1, jnp.int32),
-        ev_count=jnp.zeros((H, D), jnp.int32),
-        sat=jnp.zeros((H,), jnp.int32))
+        ev_ring=np.full((H, D, cap_ev), -1, np.int32),
+        ev_count=np.zeros((H, D), np.int32),
+        sat=np.zeros((H,), np.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -334,6 +333,6 @@ def run(spec, plan, eplan, state, t0: int, n_steps: int,
     """Scan the simulation; returns (state, raster[T, H, N], timings) —
     the same contract as `engine.run`."""
     step = make_step_fn(spec, plan, eplan, c_post=c_post, c_src=c_src)
-    ts = jnp.arange(t0, t0 + n_steps, dtype=jnp.int32)
+    ts = t0 + jnp.arange(n_steps, dtype=jnp.int32)
     state, (raster, tm) = jax.lax.scan(step, state, ts)
     return state, raster, tm

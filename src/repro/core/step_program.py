@@ -145,9 +145,10 @@ class StepProgram:
         return self._state0
 
     def place(self, state):
-        """Shard `state` over the mesh (identity when mesh=None)."""
+        """Put host `state` on the device(s): each shard on its device of
+        the mesh, or all shards on the default device when mesh=None."""
         if self.mesh is None:
-            return state
+            return jax.device_put(state)
         return dist_sharding.shard_put(self.mesh, state, "cells")
 
     def load(self, path: str):
@@ -184,23 +185,46 @@ class StepProgram:
         mesh=None runs the single-device reference driver (vmap shards,
         global-mask exchange — the physics definition both schedules must
         reproduce); with a mesh it is the shard_map program honouring
-        exchange/schedule."""
-        if self.mesh is None:
-            if self.splan is not None:
-                return stream_engine.run(self.spec, self.plan, self.splan,
-                                         state, t0, n_steps)
-            if self.eplan is not None:
-                return event_engine.run(
-                    self.spec, self.plan, self.eplan, state, t0, n_steps,
-                    c_post=self.caps.get("c_post"),
-                    c_src=self.caps.get("c_src"))
-            return engine.run(self.spec, self.plan, state, t0, n_steps)
+        exchange/schedule.  Either is compiled once per `n_steps`."""
+        return self._run_program().run(state, t0, n_steps)
+
+    def lower_run(self, state, t0: int, n_steps: int):
+        """The `jax.stages.Lowered` of the program `run` executes for these
+        arguments: its `.compile()` gives the compiled text (which kernels
+        entered the program) and the memory analysis."""
+        return self._run_program().lower(state, t0, n_steps)
+
+    def _run_program(self) -> distributed.RunProgram:
         if self._run is None:
-            self._run = distributed.make_run_program(
-                self.spec, self.plan, self.mesh, eplan=self.eplan,
-                caps=self.caps, hier_groups=self.hier_groups,
-                splan=self.splan)
-        return self._run(state, t0, n_steps)
+            if self.mesh is None:
+                self._run = self._vmap_run_program()
+            else:
+                self._run = distributed.make_run_program(
+                    self.spec, self.plan, self.mesh, eplan=self.eplan,
+                    caps=self.caps, hier_groups=self.hier_groups,
+                    splan=self.splan)
+        return self._run
+
+    def _vmap_run_program(self) -> distributed.RunProgram:
+        spec, caps = self.spec, self.caps
+
+        def scan(planT, state, t0, n_steps):
+            if self.splan is not None:
+                return stream_engine.run(spec, *planT, state, t0, n_steps)
+            if self.eplan is not None:
+                return event_engine.run(spec, *planT, state, t0, n_steps,
+                                        c_post=caps.get("c_post"),
+                                        c_src=caps.get("c_src"))
+            return engine.run(spec, planT, state, t0, n_steps)
+
+        # the plan is an argument (see module doc), placed once
+        run_j = jax.jit(scan, static_argnums=3)
+        plan_d = jax.device_put(self.planT)
+        return distributed.RunProgram(
+            run=lambda state, t0, n_steps: run_j(plan_d, state,
+                                                 jnp.int32(t0), n_steps),
+            lower=lambda state, t0, n_steps: run_j.lower(
+                plan_d, state, jnp.int32(t0), n_steps))
 
     # -- phase handles (paper Table 2 split) -----------------------------
 
@@ -295,7 +319,7 @@ class StepProgram:
         spec = self.spec
         ph = distributed._delivery_phases(spec, self._stim_k, self.caps)
         exchange = self._vmap_exchange()
-        planT = self.planT
+        planT = jax.device_put(self.planT)
 
         a_j = jax.jit(lambda pT, s, t: jax.vmap(
             ph.pa, in_axes=(0, 0, None))(pT, s, t))

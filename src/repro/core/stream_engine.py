@@ -197,10 +197,12 @@ def make_chunk_tables(spec: SimSpec, plan: ShardPlan):
         sh, sl = _c64(lanes[lane])
         return _splitmix64(ch ^ sh, cl ^ sl)
 
+    src_gid = jnp.asarray(plan.src_gid)      # the plan may be host arrays
+
     def tables(c, cand_row, with_j: bool = False) -> ChunkTables:
         cvalid = cand_row >= 0                               # [c_cap]
         sidx = jnp.where(cvalid, cand_row, 0)
-        g = jnp.where(cvalid, plan.src_gid[sidx], 0)         # [c_cap] int32
+        g = jnp.where(cvalid, src_gid[sidx], 0)              # [c_cap] int32
         g_u = g.astype(jnp.uint32)
 
         # counter = g * M + j (64-bit, exact)
@@ -474,8 +476,9 @@ def build(cfg: GridConfig, eng: EngineConfig,
         splans.append(StreamedPlan(cand=sh.cand,
                                    e_start=sh.e_start.astype(np.int32)))
 
-    plan = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *plans)
-    splan = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *splans)
+    # host arrays until StepProgram.place (see engine.build)
+    plan = jax.tree.map(lambda *xs: np.stack(xs), *plans)
+    splan = jax.tree.map(lambda *xs: np.stack(xs), *splans)
     spec = SimSpec(cfg=cfg, eng=eng, izh=izh, stdp=stdp, n_local=n_cap,
                    e_cap=e_pad, s_cap=s_cap, n_total=cfg.n_neurons,
                    stream=StreamSpec(chunk_cols=chunk_cols, q=q,
@@ -484,26 +487,22 @@ def build(cfg: GridConfig, eng: EngineConfig,
     w0 = np.zeros((H, e_pad), np.float32)
     for h, sh in enumerate(shards):
         w0[h, :sh.n_valid] = sh.weight0
-    state = init_state(spec, plan)._replace(w=jnp.asarray(w0))
+    state = init_state(spec, plan)._replace(w=w0)
     return spec, plan, splan, state
 
 
 def init_state(spec: SimSpec, plan: ShardPlan) -> ShardState:
-    """Fresh streamed state: synapse-state arrays sized [e_pad]."""
+    """Fresh streamed state (host arrays): synapse-state arrays sized
+    [e_pad]."""
     ss = spec.stream
     assert ss is not None
-
-    def one(p: ShardPlan) -> ShardState:
-        v = jnp.full(p.exc_mask.shape, spec.izh.v_init, jnp.float32)
-        b = jnp.where(p.exc_mask, spec.izh.b_exc, spec.izh.b_inh)
-        return ShardState(
-            v=v, u=b.astype(jnp.float32) * v,
-            last_post=jnp.full(p.exc_mask.shape, NEG_TIME),
-            w=jnp.zeros((ss.e_pad,), jnp.float32),
-            last_arr=jnp.full((ss.e_pad,), NEG_TIME),
-            arr_ring=jnp.zeros((spec.cfg.n_delay_slots, ss.e_pad), bool))
-
-    return jax.vmap(one)(plan)
+    v, u, last_post = engine.init_neurons(spec, np.asarray(plan.exc_mask))
+    H = v.shape[0]
+    return ShardState(
+        v=v, u=u, last_post=last_post,
+        w=np.zeros((H, ss.e_pad), np.float32),
+        last_arr=np.full((H, ss.e_pad), NEG_TIME),
+        arr_ring=np.zeros((H, spec.cfg.n_delay_slots, ss.e_pad), bool))
 
 
 def make_step_fn(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan):
@@ -535,7 +534,7 @@ def run(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
         s, out = step(s, t)
         return s, out
 
-    ts = jnp.arange(t0, t0 + n_steps, dtype=jnp.int32)
+    ts = t0 + jnp.arange(n_steps, dtype=jnp.int32)   # t0 may be traced
     state, (raster, tm) = jax.lax.scan(body, state, ts)
     return state, raster, tm
 

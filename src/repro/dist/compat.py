@@ -1,41 +1,27 @@
-"""shard_map / make_mesh across jax versions.
+"""The few jax entry points whose defaults this repo pins in one place.
 
-jax moved `shard_map` from `jax.experimental.shard_map` (keyword
-`check_rep`) to top-level `jax.shard_map` (keyword `check_vma`), and grew
-`jax.make_mesh` only in the later 0.4.x releases.  Every caller in this
-repo goes through `dist.shard_map(...)` / `dist.compat.make_mesh(...)` so
-the version splits live in exactly one place (exercised by the CI jax
-version matrix).
+Every caller goes through `dist.shard_map(...)` / `dist.compat.make_mesh(...)`
+so a change of jax default lands here once.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-from jax.sharding import Mesh
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, mesh, in_specs, out_specs, *, check: bool = False):
-    """Version-stable `shard_map`; `check` maps onto check_vma/check_rep."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check})
+    """`jax.shard_map`; `check` maps onto `check_vma`."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """Version-stable `jax.make_mesh` (absent before jax 0.4.35)."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    from jax.experimental import mesh_utils
-    return Mesh(mesh_utils.create_device_mesh(axis_shapes), axis_names)
+    """`jax.make_mesh` with Auto axes.  jax's default became Explicit
+    axes, under which `with_sharding_constraint` and a gather from a
+    sharded table are refused; the rule tables of `dist.sharding` leave
+    layout to the compiler and need Auto."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def process_allgather(tree):

@@ -10,10 +10,11 @@
 support every layout knob — shard counts, exchange modes, placements,
 cluster jobs, checkpointing.
 
-With --shards > 1 this process must be started with
-XLA_FLAGS=--xla_force_host_platform_device_count=<H> (or run on a real
-multi-device platform).  Under `repro.cluster.local` (the REPRO_CLUSTER_*
-env variables set), the same launcher becomes one worker of a
+With --shards > 1 this process needs that many devices: the chips of a
+multi-chip host, or on the CPU forced host devices
+(XLA_FLAGS=--xla_force_host_platform_device_count=<H>).  Under
+`repro.cluster.local` (the REPRO_CLUSTER_* env variables set), the same
+launcher becomes one worker of a
 multi-process job: `--shards` then counts GLOBAL shards across all
 processes, rasters are gathered for the rate report, and only process 0
 writes checkpoints.
@@ -25,14 +26,14 @@ import os
 
 from repro.cluster import runtime as cluster_runtime
 
-# Joining a cluster job must precede ANY jax computation — repro.core
-# builds module-level constants (engine.NEG_TIME) at import.  No-op
-# outside a cluster job (REPRO_CLUSTER_* absent).
+# Joining a cluster job must precede ANY jax computation.  No-op outside
+# a cluster job (REPRO_CLUSTER_* absent).
 cluster_runtime.ensure_initialized()
 
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (EngineConfig, GridConfig, StepProgram, checkpoint,
                         observables, profiles)
 from repro.core import distributed as D
@@ -81,7 +82,11 @@ def main():
                        placement=args.placement, delivery=args.delivery,
                        connectivity=args.connectivity_mode)
     prof = profiles.from_config(cfg)       # fail fast on a bad spec
+    compile_cache.enable()
     if cluster_runtime.is_primary():
+        dev = jax.devices()[0]
+        print(f"[snn] platform {dev.platform}, {dev.device_kind}, "
+              f"{jax.device_count()} devices")
         procs = (f", {jax.process_count()} processes"
                  if cluster_runtime.is_distributed() else "")
         print(f"[snn] {cfg.n_neurons} neurons / {cfg.n_synapses} synapses "
@@ -96,9 +101,14 @@ def main():
     sharded = args.shards > 1
     if sharded:
         # jax.devices() is global: across every process of a cluster job
-        assert len(jax.devices()) >= args.shards, \
-            "set XLA_FLAGS=--xla_force_host_platform_device_count " \
-            "or launch more processes (repro.cluster.local)"
+        if jax.device_count() < args.shards:
+            raise SystemExit(
+                f"--shards {args.shards} needs {args.shards} devices, "
+                f"found {jax.device_count()} ({jax.default_backend()}): "
+                f"run on a host with that many chips, launch more "
+                f"processes (repro.cluster.local), or on the CPU force "
+                f"host devices with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={args.shards}")
     sp = StepProgram(cfg, eng,
                      mesh=D.make_mesh(args.shards) if sharded else None)
     spec, plan, state = sp.spec, sp.plan, sp.init_state()

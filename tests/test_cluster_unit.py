@@ -43,6 +43,17 @@ def test_cluster_env_respects_explicit_collectives(monkeypatch):
     assert env["JAX_CPU_COLLECTIVES_IMPLEMENTATION"] == "mpi"
 
 
+@pytest.mark.parametrize("make_env", [
+    lambda: _flags.subprocess_env(2, SRC),
+    lambda: _flags.cluster_env(2, SRC, coordinator="h:1", num_processes=2,
+                               process_id=1),
+], ids=["subprocess_env", "cluster_env"])
+def test_child_env_pins_cpu_platform(monkeypatch, make_env):
+    # a parent holding an accelerator must not hand it to its CPU ranks
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert make_env()["JAX_PLATFORMS"] == "cpu"
+
+
 def test_runtime_from_env_roundtrip(monkeypatch):
     for v in (_flags.ENV_COORD, _flags.ENV_NUM_PROCS, _flags.ENV_PROC_ID):
         monkeypatch.delenv(v, raising=False)

@@ -160,7 +160,7 @@ class TestDelays:
         step = E.make_step_fn(spec, plan)
 
         # force neuron 0 to spike at t=0 by injecting via v
-        state = state._replace(v=state.v.at[0, 0].set(40.0))
+        state = state._replace(v=jnp.asarray(state.v).at[0, 0].set(40.0))
         arrivals = []
         for t in range(8):
             state, (spiked, tm) = jax.jit(step)(state, jnp.int32(t))

@@ -76,6 +76,49 @@ def test_serve_cli_smoke():
     assert "[serve] 2 requests" in out
 
 
+@pytest.mark.parametrize("where", ["checkout", "script_alone"])
+def test_chip_smoke_fails_without_tpu(tmp_path, where):
+    """chip_smoke.py exits non-zero and prints no result line when JAX
+    finds no TPU, and when it stands outside a checkout."""
+    import shutil
+    import subprocess
+    import sys
+    script = os.path.join(os.path.dirname(SRC), "chip_smoke.py")
+    if where == "script_alone":
+        script = shutil.copy(script, tmp_path)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, script], capture_output=True,
+                         text=True, env=env, cwd=tmp_path, timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "FAILED" in out.stderr
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/cache"],
+                         ids=["checkout", "env"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The cache follows JAX_COMPILATION_CACHE_DIR when set (jax reads it
+    itself) and is <checkout>/.jax_cache otherwise — a fixed path."""
+    import jax
+
+    from repro import compile_cache
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV, env_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable()
+        if env_dir is None:
+            assert got == os.path.join(os.path.dirname(SRC), ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 @pytest.mark.slow
 def test_dryrun_cli_one_cell():
     """The real dry-run driver end to end on the cheapest cell (its own
