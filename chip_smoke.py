@@ -41,11 +41,13 @@ SRC = os.path.join(ROOT, "src")
 
 # Paper widths are GridConfig's defaults (core/params.py): 1000 Izhikevich
 # neurons per column, M = 200 synapses per neuron, ring3, STDP on.
-# Step counts are short because the dense step is slow on the chip: its
-# E-wide gathers and scatter-add take seconds per step at 115.2 M synapses
-# on a TPU v5e (ROADMAP S2), and the whole script has to finish well inside
-# 20 minutes.  The four-chip phase uses a 12x12 grid for the same reason:
-# its one-chip reference runs the whole network on one chip.
+# The cuts are for time, not memory.  The dense step takes about 6.5 s at
+# 115.2 M synapses on a TPU v5e (cause not yet traced, ROADMAP S2), so 200
+# steps would take 22 minutes; the script has to finish well inside 20
+# minutes, so the main path runs 10.  The four-chip phase uses a 12x12
+# grid to keep a four-chip call short: a 24x24 one pays about 3 minutes of
+# host build and about 65 s of one-chip reference at four chips' rate.
+# The 24x24 four-chip check is still to be run.
 MAIN_GRID = (24, 24)
 MAIN_STEPS = 10
 TABLE1_GRID = (4, 4)
@@ -65,13 +67,20 @@ KERNEL_NEURONS = 24 * 24 * 1000
 # each difference is counted in ulps of the operation's own scale, the
 # largest magnitude the update adds or subtracts:
 #   Izhikevich v: max over both half-steps of |v| and
-#       h * (0.04 v^2 + 5 |v| + 140 + |u| + |I|); u: max(|u|, a (b |v| + |u|))
+#       h * (0.04 v^2 + 5 |v| + 140 + |u| + |I|);
+#     u: max(|u|, a (b |v| + |u|)), and for a neuron that spiked the reset
+#       sum u' + d as well, |u| + a (b |v| + |u|) + |d|
 #   STDP w: max(|w|, 1), the weights' order of magnitude, above the most
 #       LTD/LTP removes or adds (a_minus, a_plus <= 0.12)
-# One rounding difference per operation, amplified through the two
-# half-steps by at most 1 + h (0.08 v + 5) < 5 below threshold, stays under
-# IZH_ULP_BOUND; a float32 `exp` within 2**-19 relative gives at most 2
-# ulps of a weight of 1 at ltd <= 0.12, STDP_ULP_BOUND leaves a factor 4.
+# Izhikevich: up to two rounding differences per half-step, those of the
+# first amplified in the second by at most 1 + h (0.08 v + 5) < 5, give at
+# most 2 * 5 + 2 = 12 ulps of v's scale.  u's scale holds a b |v|, so u
+# carries v's difference at the same relative size (up to twice as many
+# ulps across a binade) plus its own three roundings: 2 * 2 + 3 = 7 ulps
+# at v's usual 2.  On a TPU v5e, seeds 0-3 read v <= 2 and u 5-7 ulps;
+# IZH_ULP_BOUND = 16 leaves a factor 2 over the largest u.
+# STDP: a float32 `exp` within 2**-19 relative gives at most 2 ulps of a
+# weight of 1 at ltd <= 0.12, STDP_ULP_BOUND leaves a factor 4.
 IZH_ULP_BOUND = 16
 STDP_ULP_BOUND = 8
 
@@ -155,16 +164,19 @@ def against_oracle(name: str, kernel, oracle, args):
     return host(got), host(want), host(args)
 
 
-def izh_scales(v, u, cur, a, b, h, substeps):
+def izh_scales(v, u, cur, a, b, d, spiked, h, substeps):
     """Per-neuron operation scales of the Izhikevich update (float64)."""
-    v, u, cur, a, b = (x.astype(np.float64) for x in (v, u, cur, a, b))
+    v, u, cur, a, b, d = (x.astype(np.float64) for x in (v, u, cur, a, b, d))
     sv = np.abs(v)
     for _ in range(substeps):
         terms = 0.04 * v * v + 5 * np.abs(v) + 140 + np.abs(u) + np.abs(cur)
         sv = np.maximum(sv, h * terms)
         v = v + h * (0.04 * v * v + 5 * v + 140 - u + cur)
         sv = np.maximum(sv, np.abs(v))
-    return sv, np.maximum(np.abs(u), np.abs(a) * (np.abs(b) * sv + np.abs(u)))
+    du = np.abs(a) * (np.abs(b) * sv + np.abs(u))
+    su = np.maximum(np.abs(u), du)
+    return sv, np.where(spiked, np.maximum(su, np.abs(u) + du + np.abs(d)),
+                        su)
 
 
 def kernel_phase(seed: int, n_syn: int, n_neur: int) -> None:
@@ -189,11 +201,11 @@ def kernel_phase(seed: int, n_syn: int, n_neur: int) -> None:
              sel(izh.a_exc, izh.a_inh), sel(izh.b_exc, izh.b_inh),
              sel(izh.c_exc, izh.c_inh), sel(izh.d_exc, izh.d_inh))
     nkw = dict(v_peak=izh.v_peak, dt=izh.dt, substeps=izh.v_substeps)
-    (vk, uk, sk), (vo, uo, so), (v, u, cur, a, b, _, _) = against_oracle(
+    (vk, uk, sk), (vo, uo, so), (v, u, cur, a, b, _, d) = against_oracle(
         "izhikevich",
         functools.partial(ops.izhikevich_update, use_pallas=True, **nkw),
         functools.partial(ref.izhikevich_update, **nkw), nargs)
-    sv, su = izh_scales(v, u, cur, a, b, izh.dt / izh.v_substeps,
+    sv, su = izh_scales(v, u, cur, a, b, d, so, izh.dt / izh.v_substeps,
                         izh.v_substeps)
     res = dict(izhikevich_v_ulp=ulps(vk, vo, sv),
                izhikevich_u_ulp=ulps(uk, uo, su),

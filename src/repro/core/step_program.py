@@ -113,6 +113,7 @@ class StepProgram:
         self.hier_groups = hier_groups
         self._state0 = state0
         self._run = None
+        self._plan_d = None
         self._phases = None
         self._fused = None
         self._stim_k = stimulus.stim_key(spec.cfg)
@@ -134,6 +135,13 @@ class StepProgram:
         EventPlan); streamed: (ShardPlan, StreamedPlan))."""
         return distributed._plan_tree(self.spec, self.plan, self.eplan,
                                       self.splan)
+
+    def _placed_plan(self):
+        """`planT` on the default device, placed once and shared by the
+        mesh=None run and phase programs."""
+        if self._plan_d is None:
+            self._plan_d = jax.device_put(self.planT)
+        return self._plan_d
 
     def init_state(self):
         """The freshly-built initial state (host-side, unplaced)."""
@@ -217,9 +225,9 @@ class StepProgram:
                                         c_src=caps.get("c_src"))
             return engine.run(spec, planT, state, t0, n_steps)
 
-        # the plan is an argument (see module doc), placed once
+        # the plan is an argument (see module doc)
         run_j = jax.jit(scan, static_argnums=3)
-        plan_d = jax.device_put(self.planT)
+        plan_d = self._placed_plan()
         return distributed.RunProgram(
             run=lambda state, t0, n_steps: run_j(plan_d, state,
                                                  jnp.int32(t0), n_steps),
@@ -319,7 +327,7 @@ class StepProgram:
         spec = self.spec
         ph = distributed._delivery_phases(spec, self._stim_k, self.caps)
         exchange = self._vmap_exchange()
-        planT = jax.device_put(self.planT)
+        planT = self._placed_plan()
 
         a_j = jax.jit(lambda pT, s, t: jax.vmap(
             ph.pa, in_axes=(0, 0, None))(pT, s, t))

@@ -98,7 +98,11 @@ def test_chip_smoke_fails_without_tpu(tmp_path, where):
                          ids=["checkout", "env"])
 def test_compile_cache_dir(monkeypatch, env_dir):
     """The cache follows JAX_COMPILATION_CACHE_DIR when set (jax reads it
-    itself) and is <checkout>/.jax_cache otherwise — a fixed path."""
+    itself) and is <checkout>/.jax_cache otherwise — a fixed path.  Source
+    paths in kernel locations lose the checkout prefix, so the key is the
+    same wherever the checkout lies."""
+    import re
+
     import jax
 
     from repro import compile_cache
@@ -107,6 +111,7 @@ def test_compile_cache_dir(monkeypatch, env_dir):
     else:
         monkeypatch.setenv(compile_cache.ENV, env_dir)
     prev = jax.config.jax_compilation_cache_dir
+    prev_re = jax.config.jax_hlo_source_file_canonicalization_regex
     try:
         got = compile_cache.enable()
         if env_dir is None:
@@ -115,8 +120,14 @@ def test_compile_cache_dir(monkeypatch, env_dir):
         else:
             assert got == env_dir
             assert jax.config.jax_compilation_cache_dir == prev
+        kernel = os.path.join(SRC, "repro", "kernels", "stdp.py")
+        assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex,
+                      "", kernel) == os.path.join("src", "repro", "kernels",
+                                                  "stdp.py")
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          prev_re)
 
 
 @pytest.mark.slow
