@@ -99,6 +99,7 @@ def make_mesh(n_shards: int) -> Mesh:
     return dist_mesh.make_snn_mesh(n_shards)
 
 
+@engine.scope("exchange")
 def _spiked_src_allgather(spec, plan_gid_all, spiked, src_gid):
     spk_all = jax.lax.all_gather(spiked, "cells")            # [H, N]
     glob = jnp.zeros((spec.n_total,), bool).at[
@@ -106,6 +107,7 @@ def _spiked_src_allgather(spec, plan_gid_all, spiked, src_gid):
     return glob.at[src_gid].get(mode="fill", fill_value=False) & (src_gid >= 0)
 
 
+@engine.scope("exchange")
 def _spiked_src_halo(spec, offsets, plan, spiked):
     """Sparse AER wire + dense local match.
 
@@ -179,6 +181,7 @@ def hier_offsets(spec: SimSpec, plan: ShardPlan, group_size: int
     return sorted(offs)
 
 
+@engine.scope("exchange")
 def _spiked_src_hier(spec, groups, g_offsets, gid_all, plan, spiked):
     """Two-level exchange: intra-group all_gather, inter-group AER.
 

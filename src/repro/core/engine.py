@@ -36,6 +36,23 @@ from .params import (DEFAULT_IZH, DEFAULT_STDP, EngineConfig, GridConfig,
 # this module claims no device
 NEG_TIME = np.float32(-1.0e9)
 
+# The names the step's work carries in the compiled program's op metadata
+# (`jax.named_scope`): the four phases, which every execution layout and
+# delivery backend passes through, then the dense path's four E-wide index
+# operations.  The benchmark's trace join (chip_bench/scopes.py) maps each
+# device op to the innermost of these.
+SCOPES = ("phase_a_dynamics", "phase_a_plasticity", "exchange", "phase_b",
+          "gather_last_post", "current_scatter", "gather_post_spiked",
+          "gather_src_spiked")
+
+
+def scope(name: str):
+    """`jax.named_scope(name)` for a name of SCOPES; usable as a decorator.
+    Scopes change op metadata only: they add no op."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of engine.SCOPES")
+    return jax.named_scope(name)
+
 
 class ShardPlan(NamedTuple):
     """Static per-shard data (device arrays).  Leading dim stacks shards."""
@@ -208,6 +225,7 @@ class StepTimings(NamedTuple):
     arrivals: jnp.ndarray     # synaptic arrival count this step
 
 
+@scope("phase_a_dynamics")
 def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, state: ShardState,
                      t: jnp.ndarray, stim_k: jax.Array
                      ) -> Tuple[ShardState, jnp.ndarray, StepTimings]:
@@ -230,14 +248,16 @@ def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, state: ShardState,
     # 2+3. fused arrival pass: current contributions (pre-LTD weights, in
     # canonical (tgt, src, j) order => reproducible sum), LTD against the
     # nearest post spike, last_arrival refresh.
-    lp = state.last_post[plan.syn_tgt]
+    with scope("gather_last_post"):
+        lp = state.last_post[plan.syn_tgt]
     w, last_arr, contrib = kops.stdp_arrival(
         arrivals, state.w, lp, state.last_arr, plan.syn_plastic, tf,
         a_minus=stdp.a_minus, tau_minus=stdp.tau_minus, w_min=stdp.w_min,
         w_max=stdp.w_max, neg_time=float(NEG_TIME), use_pallas=up)
-    i_syn = jax.ops.segment_sum(contrib, plan.syn_tgt,
-                                num_segments=spec.n_local,
-                                indices_are_sorted=True)
+    with scope("current_scatter"):
+        i_syn = jax.ops.segment_sum(contrib, plan.syn_tgt,
+                                    num_segments=spec.n_local,
+                                    indices_are_sorted=True)
     arr_ring = state.arr_ring.at[r].set(False)
 
     # 4+5. stimulus + Izhikevich (shared with the streamed driver)
@@ -281,6 +301,7 @@ def neuron_update(spec: SimSpec, plan: ShardPlan, state: ShardState,
     return v, u, spiked
 
 
+@scope("phase_a_plasticity")
 def phase_a_plasticity(spec: SimSpec, plan: ShardPlan, state: ShardState,
                        spiked: jnp.ndarray, t: jnp.ndarray) -> ShardState:
     """Phase A step 6: LTP for incoming synapses of spiking neurons.
@@ -295,7 +316,8 @@ def phase_a_plasticity(spec: SimSpec, plan: ShardPlan, state: ShardState,
     stdp = spec.stdp
     up = spec.eng.use_pallas or None
     tf = t.astype(jnp.float32)
-    post = spiked[plan.syn_tgt]
+    with scope("gather_post_spiked"):
+        post = spiked[plan.syn_tgt]
     w = kops.stdp_ltp(post, state.w, state.last_arr, plan.syn_plastic,
                       plan.syn_valid, tf, a_plus=stdp.a_plus,
                       tau_plus=stdp.tau_plus, w_min=stdp.w_min,
@@ -320,6 +342,7 @@ def phase_a(spec: SimSpec, plan: ShardPlan, state: ShardState,
     return state, spiked, tm
 
 
+@scope("phase_b")
 def phase_b(spec: SimSpec, plan: ShardPlan, state: ShardState,
             spiked_src: jnp.ndarray, t: jnp.ndarray) -> ShardState:
     """Deferred axonal arborization: set arrival flags at t + delay.
@@ -330,7 +353,9 @@ def phase_b(spec: SimSpec, plan: ShardPlan, state: ShardState,
     formulation is D fused selects (EXPERIMENTS.md §Perf, SNN iteration).
     """
     D = spec.cfg.n_delay_slots
-    active = spiked_src[plan.syn_src] & plan.syn_valid       # [E]
+    with scope("gather_src_spiked"):
+        active = spiked_src[plan.syn_src]
+    active = active & plan.syn_valid                         # [E]
     slot = jnp.mod(t + plan.syn_delay, D)                    # [E]
     hit = active[None, :] & (slot[None, :]
                              == jnp.arange(D, dtype=slot.dtype)[:, None])
@@ -350,6 +375,17 @@ def _global_spike_mask(spec: SimSpec, plan: ShardPlan, spiked: jnp.ndarray
     return jnp.zeros((spec.n_total,), bool).at[gids].max(spk, mode="drop")
 
 
+@scope("exchange")
+def global_exchange(spec: SimSpec, plan: ShardPlan, spiked: jnp.ndarray
+                    ) -> jnp.ndarray:
+    """The single-device exchange: each shard's sources [H, S] read off
+    the global spike mask of the stacked [H, N] `spiked`."""
+    glob = _global_spike_mask(spec, plan, spiked)
+    return jax.vmap(
+        lambda p: glob.at[p.src_gid].get(mode="fill", fill_value=False)
+        & (p.src_gid >= 0))(plan)
+
+
 def make_step_fn(spec: SimSpec, plan: ShardPlan):
     """jit-able step over stacked shard states (single device, vmap comm)."""
     stim_k = stimulus.stim_key(spec.cfg)
@@ -357,10 +393,7 @@ def make_step_fn(spec: SimSpec, plan: ShardPlan):
     def step(state: ShardState, t: jnp.ndarray):
         state, spiked, tm = jax.vmap(
             lambda p, s: phase_a(spec, p, s, t, stim_k))(plan, state)
-        glob = _global_spike_mask(spec, plan, spiked)        # the exchange
-        spiked_src = jax.vmap(
-            lambda p: glob.at[p.src_gid].get(mode="fill", fill_value=False)
-            & (p.src_gid >= 0))(plan)
+        spiked_src = global_exchange(spec, plan, spiked)
         state = jax.vmap(
             lambda p, s, ss: phase_b(spec, p, s, ss, t))(plan, state,
                                                          spiked_src)

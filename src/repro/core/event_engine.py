@@ -137,6 +137,7 @@ def init_event_state(spec: SimSpec, base: ShardState, cap_ev: int
 # ---------------------------------------------------------------------------
 
 
+@engine.scope("phase_a_dynamics")
 def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
                      st: EventState, t: jnp.ndarray, stim_k
                      ) -> Tuple[EventState, jnp.ndarray, StepTimings]:
@@ -195,6 +196,7 @@ def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
     return new, spiked, tm
 
 
+@engine.scope("phase_a_plasticity")
 def phase_a_plasticity(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
                        st: EventState, spiked: jnp.ndarray, t: jnp.ndarray,
                        c_post: Optional[int] = None) -> EventState:
@@ -241,6 +243,7 @@ def phase_a(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
     return st, spiked, tm
 
 
+@engine.scope("phase_b")
 def phase_b(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
             st: EventState, spiked_src: jnp.ndarray, t: jnp.ndarray,
             c_src: Optional[int] = None) -> EventState:
@@ -316,10 +319,7 @@ def make_step_fn(spec: SimSpec, plan: ShardPlan, eplan: EventPlan,
             lambda p, ep, s: phase_a(spec, p, ep, s, t, stim_k,
                                      c_post=c_post)
         )(plan, eplan, state)
-        glob = engine._global_spike_mask(spec, plan, spiked)
-        spiked_src = jax.vmap(
-            lambda p: glob.at[p.src_gid].get(mode="fill", fill_value=False)
-            & (p.src_gid >= 0))(plan)
+        spiked_src = engine.global_exchange(spec, plan, spiked)
         state = jax.vmap(
             lambda p, ep, s, ss: phase_b(spec, p, ep, s, ss, t, c_src=c_src)
         )(plan, eplan, state, spiked_src)

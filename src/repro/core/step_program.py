@@ -55,6 +55,9 @@ from .params import (DEFAULT_IZH, DEFAULT_STDP, EngineConfig, GridConfig,
                      IzhikevichParams, StdpParams)
 from ..dist import sharding as dist_sharding
 
+# the host span `run` opens, on the profiler's clock
+RUN_SPAN = "run_call"
+
 
 class StepProgram:
     """Run/phase/timing handles for one (GridConfig, EngineConfig, mesh).
@@ -193,8 +196,13 @@ class StepProgram:
         mesh=None runs the single-device reference driver (vmap shards,
         global-mask exchange — the physics definition both schedules must
         reproduce); with a mesh it is the shard_map program honouring
-        exchange/schedule.  Either is compiled once per `n_steps`."""
-        return self._run_program().run(state, t0, n_steps)
+        exchange/schedule.  Either is compiled once per `n_steps`.
+
+        The call is the host span RUN_SPAN in a profiler trace: argument
+        preparation and dispatch, not the device's work, which it does
+        not wait for."""
+        with jax.profiler.TraceAnnotation(RUN_SPAN):
+            return self._run_program().run(state, t0, n_steps)
 
     def lower_run(self, state, t0: int, n_steps: int):
         """The `jax.stages.Lowered` of the program `run` executes for these
@@ -262,15 +270,13 @@ class StepProgram:
         spec, plan = self.spec, self.plan
 
         def ex_allgather(planT, spiked):
-            p = distributed._base_plan(planT)
-            glob = engine._global_spike_mask(spec, p, spiked)
-            return jax.vmap(
-                lambda p1: glob.at[p1.src_gid].get(
-                    mode="fill", fill_value=False) & (p1.src_gid >= 0))(p)
+            return engine.global_exchange(spec, distributed._base_plan(planT),
+                                          spiked)
 
         if spec.eng.exchange == "halo":
             offsets = distributed.halo_offsets(spec, plan)
 
+            @engine.scope("exchange")
             def ex_halo(planT, spiked):
                 p = distributed._base_plan(planT)
                 ids_all, _ = jax.vmap(
@@ -298,6 +304,7 @@ class StepProgram:
             G = len(groups)
             g_offsets = distributed.hier_offsets(spec, plan, L)
 
+            @engine.scope("exchange")
             def ex_hier(planT, spiked):
                 p = distributed._base_plan(planT)
                 N = spiked.shape[-1]

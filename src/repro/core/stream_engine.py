@@ -286,6 +286,7 @@ def _chunk_xs(spec: SimSpec, splan: StreamedPlan):
     return cs, splan.cand, splan.e_start[:-1]
 
 
+@engine.scope("phase_a_dynamics")
 def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
                      state: ShardState, t: jnp.ndarray, stim_k: jax.Array
                      ) -> Tuple[ShardState, jnp.ndarray, StepTimings]:
@@ -351,6 +352,7 @@ def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
     return new, spiked, tm
 
 
+@engine.scope("phase_a_plasticity")
 def phase_a_plasticity(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
                        state: ShardState, spiked: jnp.ndarray,
                        t: jnp.ndarray) -> ShardState:
@@ -388,6 +390,7 @@ def phase_a(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
     return state, spiked, tm
 
 
+@engine.scope("phase_b")
 def phase_b(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan,
             state: ShardState, spiked_src: jnp.ndarray, t: jnp.ndarray
             ) -> ShardState:
@@ -513,10 +516,7 @@ def make_step_fn(spec: SimSpec, plan: ShardPlan, splan: StreamedPlan):
         state, spiked, tm = jax.vmap(
             lambda p, sp, s: phase_a(spec, p, sp, s, t, stim_k)
         )(plan, splan, state)
-        glob = engine._global_spike_mask(spec, plan, spiked)
-        spiked_src = jax.vmap(
-            lambda p: glob.at[p.src_gid].get(mode="fill", fill_value=False)
-            & (p.src_gid >= 0))(plan)
+        spiked_src = engine.global_exchange(spec, plan, spiked)
         state = jax.vmap(
             lambda p, sp, s, ssrc: phase_b(spec, p, sp, s, ssrc, t)
         )(plan, splan, state, spiked_src)

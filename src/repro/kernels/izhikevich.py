@@ -53,4 +53,5 @@ def izhikevich_update(v, u, current, a, b, c, d, *, v_peak: float,
                    jax.ShapeDtypeStruct(v.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.bool_)),
         interpret=interpret,
+        name="izhikevich",
     )(v, u, current, a, b, c, d)

@@ -75,6 +75,7 @@ def stdp_arrival(arr, w, last_post_g, last_arr, plastic, t, *,
                    jax.ShapeDtypeStruct(w.shape, jnp.float32),
                    jax.ShapeDtypeStruct(w.shape, jnp.float32)),
         interpret=interpret,
+        name="stdp_arrival",
     )(arr, w, last_post_g, last_arr, plastic, t)
 
 
@@ -94,4 +95,5 @@ def stdp_ltp(post_g, w, last_arr, plastic, valid, t, *,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(w.shape, jnp.float32),
         interpret=interpret,
+        name="stdp_ltp",
     )(post_g, w, last_arr, plastic, valid, t)

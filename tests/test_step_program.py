@@ -199,3 +199,110 @@ class TestDeprecatedShims:
             warnings.simplefilter("error", DeprecationWarning)
             sp = StepProgram(CFG, EngineConfig(n_shards=2))
             sp.run(sp.init_state(), 0, 5)
+
+
+# ---------------------------------------------------------------------------
+# the step's work, named: scopes, the run span, the counters
+# ---------------------------------------------------------------------------
+
+PHASES = ("phase_a_dynamics", "phase_a_plasticity", "exchange", "phase_b")
+
+
+def _op_names(sp, n_steps=2):
+    """The op_name metadata of the CPU-compiled run program."""
+    import re
+    state = sp.place(sp.init_state())
+    text = sp.lower_run(state, 0, n_steps).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _scoped(op_names, name):
+    import re
+    return any(re.search(rf"(?<!\w){name}(?!\w)", o) for o in op_names)
+
+
+@pytest.mark.parametrize("layout", ["dense", "event", "streamed", "mesh"])
+def test_each_layout_names_its_phases(layout):
+    """Every execution layout and delivery backend carries the four phase
+    scopes of engine.SCOPES into the compiled program; the dense path
+    also scopes its four E-wide index operations."""
+    eng = {"dense": EngineConfig(n_shards=2),
+           "event": EngineConfig(n_shards=2, delivery="event"),
+           "streamed": EngineConfig(n_shards=2,
+                                    connectivity="streamed:chunk=2"),
+           "mesh": EngineConfig(n_shards=1)}[layout]
+    mesh = D.make_mesh(1) if layout == "mesh" else None
+    names = _op_names(StepProgram(CFG, eng, mesh=mesh))
+    want = PHASES
+    if layout in ("dense", "mesh"):
+        want = core.engine.SCOPES
+    assert set(PHASES) <= set(core.engine.SCOPES)
+    assert [s for s in want if not _scoped(names, s)] == []
+
+
+def test_scope_outside_the_list_is_refused():
+    with pytest.raises(ValueError, match="SCOPES"):
+        core.engine.scope("gather_everything")
+
+
+def test_run_counters_match_an_independent_count():
+    """StepProgram.run's per-step StepTimings, summed over chunks as the
+    chip benchmark sums them, equal a numpy count from the raster and the
+    synapse tables: spikes per step, and synapses whose source spiked
+    `delay` steps earlier.  2x2 columns at the paper's widths, 20 steps
+    in two chunks."""
+    cfg = GridConfig(grid_x=2, grid_y=2)
+    sp = StepProgram(cfg, EngineConfig())
+    state = sp.place(sp.init_state())
+    rasters, spikes, arrivals = [], [], []
+    for t0 in (0, 10):
+        state, raster, tm = sp.run(state, t0, 10)
+        rasters.append(np.asarray(raster)[:, 0])
+        spikes.append(np.asarray(tm.spikes)[:, 0])
+        arrivals.append(np.asarray(tm.arrivals)[:, 0])
+    raster = np.concatenate(rasters)                       # [T, N]
+    spikes, arrivals = np.concatenate(spikes), np.concatenate(arrivals)
+
+    plan = sp.plan
+    gid = np.asarray(plan.gid)[0]
+    by_gid = np.zeros((raster.shape[0], cfg.n_neurons), bool)
+    by_gid[:, gid[gid >= 0]] = raster[:, gid >= 0]
+    valid = np.asarray(plan.syn_valid)[0]
+    src = np.asarray(plan.src_gid)[0][np.asarray(plan.syn_src)[0][valid]]
+    delay = np.asarray(plan.syn_delay)[0][valid]
+    want_arrivals = np.array([
+        int(by_gid[t - delay[delay <= t], src[delay <= t]].sum())
+        for t in range(raster.shape[0])])
+
+    assert spikes.sum() > 0 and want_arrivals.sum() > 0
+    assert np.array_equal(spikes, raster.sum(axis=1))
+    assert np.array_equal(arrivals, want_arrivals)
+
+
+def test_run_call_is_a_host_span_in_the_profile(tmp_path):
+    """StepProgram.run writes its RUN_SPAN into a profiler trace, once a
+    call, on the host's clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.step_program import RUN_SPAN
+
+    sp = StepProgram(CFG, EngineConfig(n_shards=2))
+    state = sp.place(sp.init_state())
+    state = jax.block_until_ready(sp.run(state, 0, 1))[0]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for t in (1, 2, 3):
+            state = jax.block_until_ready(sp.run(state, t, 1))[0]
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = [e for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == RUN_SPAN]
+    assert len(spans) == 3
+    assert all(e.duration_ns > 0 for e in spans)

@@ -8,6 +8,7 @@ library, and test workers import every test file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import EngineConfig, GridConfig, StepProgram
-from repro.core.engine import NEG_TIME
+from repro.core.engine import NEG_TIME, SCOPES
 from repro.core.params import DEFAULT_IZH as IZH
 from repro.core.params import DEFAULT_STDP as STDP
 from repro.kernels import ops
@@ -97,13 +98,36 @@ def test_kernel_compiles_at_paper_shard_width(monkeypatch, one_chip, make):
     _compile_for_chip(jax.jit(fn), *args)
 
 
-def test_fused_step_compiles_with_kernels(monkeypatch, one_chip):
-    """StepProgram's own fused step, 4x4 at paper widths: the kernels
-    enter the program once the backend reads as a TPU."""
+@pytest.fixture(scope="module")
+def fused_4x4(one_chip):
+    """StepProgram's own fused step, 4x4 at paper widths, compiled for the
+    chip once the backend reads as a TPU."""
     sp = StepProgram(GridConfig(grid_x=4, grid_y=4), EngineConfig())
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     shapes = jax.tree.map(
         lambda x: _sds(np.shape(x), np.asarray(x).dtype, one_chip),
         (sp.planT, sp.init_state()))
-    _compile_for_chip(sp.fused, *shapes,
-                      _sds((), jnp.int32, one_chip))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        return _compile_for_chip(sp.fused, *shapes,
+                                 _sds((), jnp.int32, one_chip))
+
+
+def test_fused_step_compiles_with_kernels(fused_4x4):
+    """The kernels enter the fused step, which fits one chip
+    (_compile_for_chip checks both)."""
+    assert "tpu_custom_call" in fused_4x4.as_text()
+
+
+def test_fused_step_names_its_kernels_and_scopes(fused_4x4):
+    """Each Pallas kernel is an instruction named after it, so the device
+    trace names its events so; each name of engine.SCOPES reaches the op
+    metadata that joins the trace to the program's phases."""
+    text = fused_4x4.as_text()
+    for kernel in ("izhikevich", "stdp_arrival", "stdp_ltp"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", text), \
+            kernel
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    missing = [s for s in SCOPES
+               if not any(re.search(rf"(?<!\w){s}(?!\w)", o)
+                          for o in op_names)]
+    assert missing == []
