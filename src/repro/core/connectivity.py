@@ -122,7 +122,8 @@ class ShardSynapses:
     # source table: sorted unique source gids with >=1 incoming synapse here
     src_gid: np.ndarray        # [S_cap] int64 (pad: -1)
     n_src: int
-    # synapse arrays, flat, canonical order (pad: valid=False)
+    # synapse arrays, flat, canonical order (pad: valid=False, tgt_local
+    # repeats the last valid target)
     src_idx: np.ndarray        # [E_cap] int32 -> index into src_gid
     tgt_local: np.ndarray      # [E_cap] int32 -> owned-neuron local index
     j: np.ndarray              # [E_cap] int32 forward-slot index (checkpoint key)
@@ -195,11 +196,18 @@ def build_shard(cfg: GridConfig, eng: EngineConfig, shard: int,
     src_gid_p[:S] = src_table
     return ShardSynapses(
         src_gid=src_gid_p, n_src=S,
-        src_idx=padE(src_idx), tgt_local=padE(tgt_local),
+        src_idx=padE(src_idx), tgt_local=padE(tgt_local, _tail(tgt_local, E)),
         j=padE(j.astype(np.int32)),
         delay=padE(delay.astype(np.int32), 1),
         weight0=padE(weight), plastic=padE(plastic),
         valid=padE(np.ones(E, dtype=bool)), n_valid=E)
+
+
+def _tail(tgt_local: np.ndarray, n_valid: int) -> int:
+    """Fill for the padded tail of `tgt_local`: the last valid target, so
+    the padded array stays non-decreasing (canonical order is target-major,
+    and `segment_sum` and dense delivery's target windows rely on it)."""
+    return int(tgt_local[n_valid - 1]) if n_valid else 0
 
 
 def repad_shard(t: ShardSynapses, e_cap: int, s_cap: int) -> ShardSynapses:
@@ -215,7 +223,8 @@ def repad_shard(t: ShardSynapses, e_cap: int, s_cap: int) -> ShardSynapses:
     src_gid[:t.n_src] = t.src_gid[:t.n_src]
     return ShardSynapses(
         src_gid=src_gid, n_src=t.n_src,
-        src_idx=padE(t.src_idx), tgt_local=padE(t.tgt_local),
+        src_idx=padE(t.src_idx),
+        tgt_local=padE(t.tgt_local, _tail(t.tgt_local, t.n_valid)),
         j=padE(t.j), delay=padE(t.delay, 1), weight0=padE(t.weight0),
         plastic=padE(t.plastic), valid=padE(t.valid), n_valid=t.n_valid)
 

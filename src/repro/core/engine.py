@@ -22,7 +22,8 @@ and under `shard_map` (real collectives — repro.core.distributed).
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Tuple
+import sys
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +37,17 @@ from .params import (DEFAULT_IZH, DEFAULT_STDP, EngineConfig, GridConfig,
 # this module claims no device
 NEG_TIME = np.float32(-1.0e9)
 
+# Dense delivery's target windows (`expand_to_synapses`): blocks of
+# TGT_BLOCK consecutive synapses, one row of 128 lanes, each reaching at
+# most TGT_WINDOW_CAP consecutive targets, or the plain gather.
+TGT_BLOCK = 128
+TGT_WINDOW_CAP = 64
+
 # The names the step's work carries in the compiled program's op metadata
 # (`jax.named_scope`): the four phases, which every execution layout and
-# delivery backend passes through, then the dense path's four E-wide index
-# operations.  The benchmark's trace join (chip_bench/scopes.py) maps each
-# device op to the innermost of these.
+# delivery backend passes through, then the dense path's four index
+# operations over the synapses.  The benchmark's trace join
+# (chip_bench/scopes.py) maps each device op to the innermost of these.
 SCOPES = ("phase_a_dynamics", "phase_a_plasticity", "exchange", "phase_b",
           "gather_last_post", "current_scatter", "gather_post_spiked",
           "gather_src_spiked")
@@ -96,6 +103,11 @@ class SimSpec(NamedTuple):
     # None for materialized tables; when set, e_cap is the padded
     # synapse-STATE length and the ShardPlan syn_* leaves are dummies.
     stream: object = None
+    # dense delivery's target windows (`tgt_windows`): every block of
+    # tgt_block consecutive synapses reaches targets [lowest, lowest +
+    # tgt_window); None -> the plain E-wide gather
+    tgt_block: Optional[int] = None
+    tgt_window: Optional[int] = None
 
 
 # ----------------------------------------------------------------------------
@@ -152,12 +164,31 @@ def build(cfg: GridConfig, eng: EngineConfig,
     # host arrays: placement (StepProgram.place) puts each shard straight
     # on its device, so no device ever holds the whole network on the way
     stacked = jax.tree.map(lambda *xs: np.stack(xs), *plans)
+    window = tgt_windows(stacked.syn_tgt)
+    print("[engine] dense delivery: "
+          + (f"tgt-window B={TGT_BLOCK} W={window}" if window
+             else "gather"), file=sys.stderr, flush=True)
     spec = SimSpec(cfg=cfg, eng=eng, izh=izh, stdp=stdp, n_local=n_cap,
-                   e_cap=e_cap, s_cap=s_cap, n_total=cfg.n_neurons)
+                   e_cap=e_cap, s_cap=s_cap, n_total=cfg.n_neurons,
+                   tgt_block=TGT_BLOCK if window else None,
+                   tgt_window=window)
 
     w0 = np.stack([t.weight0 for t in tables])
     state = init_state(spec, stacked)._replace(w=w0)
     return spec, stacked, state
+
+
+def tgt_windows(syn_tgt: np.ndarray) -> Optional[int]:
+    """W, the widest span of targets (highest - lowest + 1) of any block of
+    TGT_BLOCK consecutive synapses, over the stacked [H, E] host tables;
+    None where the dense delivery keeps the plain gather: E is not a whole
+    number of blocks, or W is over TGT_WINDOW_CAP."""
+    H, E = syn_tgt.shape
+    if E == 0 or E % TGT_BLOCK:
+        return None
+    blocks = syn_tgt.reshape(H, E // TGT_BLOCK, TGT_BLOCK)
+    window = int((blocks.max(axis=-1) - blocks.min(axis=-1)).max()) + 1
+    return window if window <= TGT_WINDOW_CAP else None
 
 
 def init_neurons(spec: SimSpec, exc_mask: np.ndarray):
@@ -219,6 +250,42 @@ def make_gid_to_local(spec: SimSpec, shard_id: jnp.ndarray) -> Callable:
 # ----------------------------------------------------------------------------
 
 
+def expand_to_synapses(spec: SimSpec, plan: ShardPlan, x: jnp.ndarray
+                       ) -> jnp.ndarray:
+    """x[plan.syn_tgt]: each neuron's value [N] over its incoming
+    synapses [E].
+
+    Each block of `spec.tgt_block` synapses reaches targets within
+    `spec.tgt_window` of its lowest (`tgt_windows`, at build; synapses
+    are stored target-major, so W stays small): gather each block's W
+    consecutive values, E/B x W elements in place of E, then pick each
+    synapse's by a chain of W - 1 selects in one elementwise pass.  The
+    same values as the gather."""
+    B, W = spec.tgt_block, spec.tgt_window
+    if B is None:
+        return x[plan.syn_tgt]
+    # the barrier ties this pass's reads of the targets to `x`: without it
+    # XLA shares the W - 1 compares between a step's two expansions and
+    # keeps them in memory as E-wide masks
+    tgt, x = jax.lax.optimization_barrier((plan.syn_tgt, x))
+    tgt = tgt.reshape(-1, B)
+    # one block a row, so a block's values broadcast along lanes only: per
+    # (8, 128) tile, the sublane broadcasts compile to tens of megabytes of
+    # program on the TPU, which the program holds in HBM.  A window that
+    # would run past the last neuron starts W before it, so the gather's
+    # clipping never moves a window away from the `d` it is read with
+    lo = jnp.minimum(tgt.min(axis=1), x.shape[0] - W)
+    xw = jax.lax.gather(
+        x, lo[:, None], jax.lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)),
+        slice_sizes=(W,), mode="clip")                 # [E/B, W]
+    d = tgt - lo[:, None]
+    out = jnp.broadcast_to(xw[:, :1], tgt.shape)
+    for k in range(1, W):
+        out = jnp.where(d == k, xw[:, k:k + 1], out)
+    return out.reshape(-1)
+
+
 class StepTimings(NamedTuple):
     """Per-phase work markers (paper Table 2 instrumentation hooks)."""
     spikes: jnp.ndarray       # local spike count this step
@@ -249,7 +316,7 @@ def phase_a_dynamics(spec: SimSpec, plan: ShardPlan, state: ShardState,
     # canonical (tgt, src, j) order => reproducible sum), LTD against the
     # nearest post spike, last_arrival refresh.
     with scope("gather_last_post"):
-        lp = state.last_post[plan.syn_tgt]
+        lp = expand_to_synapses(spec, plan, state.last_post)
     w, last_arr, contrib = kops.stdp_arrival(
         arrivals, state.w, lp, state.last_arr, plan.syn_plastic, tf,
         a_minus=stdp.a_minus, tau_minus=stdp.tau_minus, w_min=stdp.w_min,
@@ -317,7 +384,7 @@ def phase_a_plasticity(spec: SimSpec, plan: ShardPlan, state: ShardState,
     up = spec.eng.use_pallas or None
     tf = t.astype(jnp.float32)
     with scope("gather_post_spiked"):
-        post = spiked[plan.syn_tgt]
+        post = expand_to_synapses(spec, plan, spiked)
     w = kops.stdp_ltp(post, state.w, state.last_arr, plan.syn_plastic,
                       plan.syn_valid, tf, a_plus=stdp.a_plus,
                       tau_plus=stdp.tau_plus, w_min=stdp.w_min,

@@ -243,9 +243,12 @@ class CompiledRound:
     def __init__(self, spec, caps: Optional[Tuple[int, int]],
                  round_steps: int):
         # normalize the closed-over spec's seed so correctness cannot
-        # silently depend on which tenant built the program first
+        # silently depend on which tenant built the program first; the
+        # target windows are one tenant's tables', so the batched round
+        # keeps the plain gather
         self.spec = spec._replace(
-            cfg=dataclasses.replace(spec.cfg, seed=0))
+            cfg=dataclasses.replace(spec.cfg, seed=0), tgt_block=None,
+            tgt_window=None)
         self.round_steps = int(round_steps)
         self.traces = 0
         spec_n = self.spec
