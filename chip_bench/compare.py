@@ -16,6 +16,7 @@ limit was set from.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from typing import Dict
 
 import numpy as np
@@ -32,6 +33,13 @@ class Outputs:
     v: np.ndarray
     u: np.ndarray
     w: np.ndarray
+
+    def digest(self) -> str:
+        """sha256 over the four arrays' bytes, in field order."""
+        h = hashlib.sha256()
+        for a in (self.raster, self.v, self.u, self.w):
+            h.update(np.ascontiguousarray(a).tobytes())
+        return h.hexdigest()
 
 
 def readings(got: Outputs, want: Outputs) -> Dict[str, float]:

@@ -1,8 +1,9 @@
 """Share of device busy time in gathers and scatters: the dense delivery's
-E-wide gathers (last_post[tgt], spiked[tgt], spiked_src[src]) and the
-scatter-add behind segment_sum, with the small ones of the stimulus and
-the spike mask.  The trace shows them as kCustom fusions over s32 indices
-(trace_reduce.kind)."""
+one E-wide gather (spiked_src[src]), the two windowed expansions of
+last_post and spiked over the target-sorted synapses (each a gather of
+W-wide slices, one per 128-synapse block), the scatter-add behind
+segment_sum, and the small ones of the stimulus and the spike mask.  The
+trace shows them as kCustom fusions over s32 indices (trace_reduce.kind)."""
 from chip_bench import trace_reduce
 
 

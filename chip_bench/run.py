@@ -17,11 +17,16 @@ One process, in this order:
 
   1. require a TPU with the cell's chips; otherwise exit non-zero, no result
   2. turn on the persistent compilation cache (repro.compile_cache)
-  3. build the network from --seed (StepProgram), place it on the chip
+  3. build the network from --seed (StepProgram), place it on the chip; a
+     cell of several chips runs one shard per chip on a one-axis `cells`
+     mesh of exactly its devices (its configuration's n_shards equals its
+     chips, and its placement is block), and every state leaf is checked
+     to be split one shard row per device
   4. compile the cell's run program; it must hold the Pallas kernels
   5. drive the network from rest through the compared steps, chunk by
      chunk, with the window's own call (this warms it up), and copy the
-     raster, v, u and w to the host: everything up to here is set-up
+     whole network's raster, v, u and w to the host, each value at its
+     global index: everything up to here is set-up
   6. the window: StepProgram.run chunk after chunk, carrying the state,
      each chunk ended by block_until_ready, until --seconds have passed;
      with --trace 1 the window is profiled
@@ -192,6 +197,7 @@ class Record:
     delay_slots: int
     peak_bytes: Optional[int]
     device_kind: str
+    chips: int = 1                # devices the network is split over
     trace: object = None          # trace_reduce.Reduction with --trace 1
 
     def peak(self, key: str) -> float:
@@ -205,7 +211,25 @@ def _span(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def _program(cell: Cell, seed: int):
+def check_layout(cell: Cell) -> None:
+    """A cell runs one shard per chip, block-placed, or not at all: exit
+    non-zero before anything is built."""
+    eng = cell.config["engine"]
+    if int(eng["n_shards"]) != cell.chips:
+        raise SystemExit(f"{cell.name}: the configuration has "
+                         f"{eng['n_shards']} shards for {cell.chips} chips; "
+                         f"a cell runs one shard per chip")
+    if eng["placement"] != "block":
+        # the fetch concatenates each shard's synapses in shard order,
+        # which is the reference's global order only when the shards hold
+        # ascending ranges of neurons
+        raise SystemExit(f"{cell.name}: placement {eng['placement']!r}; "
+                         f"the benchmark takes block placement only")
+
+
+def _program(cell: Cell, seed: int, devices=None):
+    """(GridConfig, StepProgram) of the cell from the seed; on a mesh of
+    `devices` where the cell has more than one chip."""
     from repro.core.params import (EngineConfig, GridConfig,
                                    IzhikevichParams, StdpParams)
     from repro.core.step_program import StepProgram
@@ -217,9 +241,59 @@ def _program(cell: Cell, seed: int):
                      stim_events_per_ms_per_column=tr[
                          "stim_events_per_ms_per_column"],
                      stim_amplitude=tr["stim_amplitude"])
-    return cfg, StepProgram(cfg, EngineConfig(**conf["engine"]),
+    mesh = None
+    if cell.chips > 1:
+        from jax.sharding import AxisType, Mesh
+        mesh = Mesh(np.array(devices[:cell.chips]), ("cells",),
+                    axis_types=(AxisType.Auto,))
+    return cfg, StepProgram(cfg, EngineConfig(**conf["engine"]), mesh=mesh,
                             izh=IzhikevichParams(**conf["izhikevich"]),
                             stdp=StdpParams(**conf["stdp"]))
+
+
+def check_split(state, devices) -> None:
+    """Every state leaf lies on the cell's devices, one shard row each."""
+    import jax
+    want = set(devices)
+    for leaf in jax.tree.leaves(state):
+        shards = leaf.addressable_shards
+        if ({s.device for s in shards} != want or len(shards) != len(want)
+                or any(s.data.shape[0] != 1 for s in shards)):
+            raise SystemExit(f"a state leaf of shape {leaf.shape} is not "
+                             f"split one shard per device over "
+                             f"{len(want)} devices")
+
+
+def fetch(plan, state, rasters, n_neurons: int,
+          n_synapses: int) -> compare.Outputs:
+    """The whole network's outputs on the host, in the reference's order:
+    each neuron's raster column, v and u at its global id (`plan.gid`),
+    and each shard's valid weights, concatenated in shard order.  Block
+    placement gives each shard an ascending range of targets, and each
+    shard holds its synapses in (target, source, slot) order, so that is
+    the reference's canonical order."""
+    gid = np.asarray(plan.gid)                        # [H, N_local]
+    own = gid >= 0
+    ids = gid[own]
+    if not np.array_equal(np.sort(ids), np.arange(n_neurons)):
+        raise SystemExit(f"the shards' neurons are not each of the "
+                         f"{n_neurons} neurons once")
+
+    def spread(x, lead=()):
+        out = np.zeros(lead + (n_neurons,), x.dtype)
+        out[..., ids] = x[..., own]
+        return out
+
+    raster = np.concatenate([np.asarray(r) for r in rasters])  # [T, H, N]
+    valid = np.asarray(plan.syn_valid)                # [H, E]
+    w_all = np.asarray(state.w)
+    w = np.concatenate([w_all[h][valid[h]] for h in range(w_all.shape[0])])
+    if w.shape[0] != n_synapses:
+        raise SystemExit(f"the program holds {w.shape[0]} weights for "
+                         f"{n_synapses} synapses")
+    return compare.Outputs(raster=spread(raster, raster.shape[:1]),
+                           v=spread(np.asarray(state.v)),
+                           u=spread(np.asarray(state.u)), w=w)
 
 
 def simulate(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -229,13 +303,14 @@ def simulate(cell: Cell, seed: int, seconds: float, trace: bool,
 
     from repro import compile_cache
 
+    check_layout(cell)
     compile_cache.enable()
     setup: Dict[str, float] = {}
     chunk = int(cell.traffic["chunk_steps"])
 
     t0 = time.perf_counter()
     with _span("build"):
-        cfg, sp = _program(cell, seed)
+        cfg, sp = _program(cell, seed, devices)
         state = sp.init_state()
     setup["build_s"] = time.perf_counter() - t0
     log(f"built in {setup['build_s']} s; host peak {host_peak_gib()} GiB")
@@ -244,6 +319,7 @@ def simulate(cell: Cell, seed: int, seconds: float, trace: bool,
     with _span("place"):
         state = jax.block_until_ready(sp.place(state))
     setup["place_s"] = time.perf_counter() - t0
+    check_split(state, devices)
 
     t0 = time.perf_counter()
     with _span("compile"):
@@ -268,15 +344,8 @@ def simulate(cell: Cell, seed: int, seconds: float, trace: bool,
     t0 = time.perf_counter()
     with _span("fetch"):
         n_syn = cfg.n_synapses
-        w = np.asarray(state.w)[0]
-        if w.shape[0] < n_syn:
-            raise SystemExit(f"the program holds {w.shape[0]} weights for "
-                             f"{n_syn} synapses")
-        got = compare.Outputs(
-            raster=np.concatenate([np.asarray(r)[:, 0] for r in rasters]),
-            v=np.asarray(state.v)[0], u=np.asarray(state.u)[0],
-            w=w[:n_syn])
-        del w, raster, rasters
+        got = fetch(sp.plan, state, rasters, cfg.n_neurons, n_syn)
+        del raster, rasters
     setup["fetch_s"] = time.perf_counter() - t0
     setup_s = time.perf_counter() - T_START
 
@@ -304,7 +373,7 @@ def simulate(cell: Cell, seed: int, seconds: float, trace: bool,
                  steps=t - n_cmp, dt_ms=cell.config["izhikevich"]["dt"],
                  n_neurons=cfg.n_neurons, n_synapses=n_syn,
                  delay_slots=cfg.n_delay_slots, peak_bytes=peak,
-                 device_kind=devices[0].device_kind)
+                 device_kind=devices[0].device_kind, chips=len(devices))
     if trace:
         from chip_bench import trace_reduce
         try:
@@ -364,6 +433,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     sim_s = got.raster.shape[0] * rec.dt_ms / 1000.0
     rate = spikes / (rec.n_neurons * sim_s)
     log(f"setup {json.dumps(rec.setup)} setup_s {rec.setup_s}")
+    # equal digests on two layouts of one seed: the same bits (Table 1)
+    log(f"compared outputs sha256 {got.digest()}")
     log(f"window {rec.window_s} s, {rec.steps} steps from step "
         f"{got.raster.shape[0]}; compared steps 0-{got.raster.shape[0] - 1}: "
         f"{spikes} spikes, rate {rate} Hz; reference counts "
@@ -388,9 +459,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
               "failed": 0 if ok else steps,
               "metrics": metrics, "device": device}
     if trace:
-        device["busy_s"] = rec.trace.busy_s
-        device["window_s"] = rec.trace.window_s
-        result["breakdown"] = rec.trace.breakdown()
+        from chip_bench import trace_reduce
+        t = rec.trace
+        device["busy_s"] = t.busy_s
+        device["window_s"] = t.window_s
+        result["breakdown"] = t.breakdown()
+        exposed = t.exposed_per_device(trace_reduce.is_collective)
+        log(f"per device: busy_s {t.busy_per_device}; exposed collective "
+            f"s {list(exposed.values())}")
     result["checks"] = checks
     for k, c in checks.items():
         print(f"check {k} {c['value']} limit {c['limit']}", file=sys.stderr)

@@ -33,8 +33,12 @@ from chip_bench import compare, kernel_bytes, reference, run  # noqa: E402
 from chip_bench import trace_reduce  # noqa: E402
 
 TRACE = os.path.join(BENCH, "testdata", "trace_4x4.xplane.pb")
-CELLS = [w["name"] for w in
-         json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]]
+# two 4x4 steps as 4 shards on 4 chips, halo wire, pipelined schedule
+TRACE_H4 = os.path.join(BENCH, "testdata", "trace_4x4_h4.xplane.pb")
+WORKLOADS = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]
+CELLS = [w["name"] for w in WORKLOADS]
+# the whole runs here have one CPU device; test_multichip.py drives four
+ONE_CHIP = [w["name"] for w in WORKLOADS if w["chips"] == 1][-1]
 
 
 def small(cell_name: str, grid: int = 2, steps: int = 8) -> run.Cell:
@@ -66,9 +70,15 @@ def program_4x4():
     cfg, sp = run._program(cell, seed)
     state = sp.place(sp.init_state())
     state, raster, _ = jax.block_until_ready(sp.run(state, 0, 20))
-    return cell, seed, sp, compare.Outputs(
+    got = run.fetch(sp.plan, state, [raster], cfg.n_neurons, cfg.n_synapses)
+    # at one shard the fetch is shard 0's rows, and its first E weights
+    old = compare.Outputs(
         raster=np.asarray(raster)[:, 0], v=np.asarray(state.v)[0],
         u=np.asarray(state.u)[0], w=np.asarray(state.w)[0][:cfg.n_synapses])
+    for k in ("raster", "v", "u", "w"):
+        assert np.array_equal(getattr(got, k), getattr(old, k)), k
+        assert getattr(got, k).dtype == getattr(old, k).dtype, k
+    return cell, seed, sp, got
 
 
 def test_reference_regenerates_the_programs_tables(program_4x4):
@@ -160,7 +170,7 @@ def planted(fault):
 
 @pytest.mark.parametrize("fault", [None] + sorted(FAULTS))
 def test_run_decides_correct(fault, capsys):
-    cell = small(CELLS[-1], grid=2, steps=8)
+    cell = small(ONE_CHIP, grid=2, steps=8)
     with planted(fault):
         result = run.run(cell, 7, 0.2, False, cpu(), check_kernels=False)
     assert result["correct"] is (fault is None), result["checks"]
@@ -174,7 +184,7 @@ def test_run_decides_correct(fault, capsys):
 
 
 def test_result_line_reports_each_end_to_end_metric():
-    cell = small(CELLS[-1], grid=2, steps=4)
+    cell = small(ONE_CHIP, grid=2, steps=4)
     result = run.run(cell, 3, 0.2, False, cpu(), check_kernels=False)
     want = {m["name"] for m in cell.end_to_end} - {"peak_hbm_bytes"}
     # the CPU backend reports no peak memory; the chip does
@@ -222,9 +232,111 @@ def test_trace_breakdown_shape(trace):
     assert all(isinstance(n, str) and s > 0 for n, s in b["device_ops"])
 
 
+@pytest.fixture(scope="module")
+def trace_h4():
+    return trace_reduce.reduce(TRACE_H4, 4)
+
+
+def test_four_chip_trace_finds_the_collective_permutes(trace_h4):
+    coll = [o for o in trace_h4.ops if trace_reduce.is_collective(o)]
+    # three halo offsets, a start and a done each, two steps, four chips
+    assert len(coll) == 3 * 2 * 2 * 4
+    assert {o.device for o in coll} == {0, 1, 2, 3}
+    assert {re.sub(r"\.\d+$", "", o.name) for o in coll} == {
+        "collective-permute-start", "collective-permute-done"}
+    # nothing else in the trace is a collective, and each chip runs each
+    # kernel once a step
+    assert not [o for o in trace_h4.ops if not trace_reduce.is_collective(o)
+                and "collective-permute" in o.text.split("(", 1)[0]]
+    for k in trace_reduce.KERNELS:
+        assert trace_h4.calls(lambda o: trace_reduce.kernel_of(o) == k) == 2
+
+
+def test_four_chip_trace_exposed_exchange_share(trace_h4):
+    exposed = trace_h4.exposed_per_device(trace_reduce.is_collective)
+    assert sorted(exposed) == [0, 1, 2, 3]
+    assert all(0 < s < trace_h4.window_s for s in exposed.values())
+    share = run.reader("per_layer", "exchange_exposed_share")(
+        _record(trace_h4, chips=4))
+    assert 0 < share < 100
+    assert len(trace_h4.busy_per_device) == 4
+    assert sum(trace_h4.busy_per_device) / 4 == pytest.approx(
+        trace_h4.busy_s, rel=1e-12)
+
+
+def test_step_roofline_counts_every_chip(trace_h4):
+    one = run.reader("per_layer", "step_roofline")(_record(trace_h4))
+    four = run.reader("per_layer", "step_roofline")(
+        _record(trace_h4, chips=4))
+    assert four == pytest.approx(one / 4, rel=1e-12)
+
+
+# what the readers gave on the two one-chip traces before the reduction
+# learnt collectives: no one-chip reading may move
+PINNED = {
+    "trace_4x4.xplane.pb": dict(
+        device_idle_share=2.621492185150276,
+        gather_scatter_share=97.06124945784029,
+        izhikevich_roofline=28.111686600102853,
+        stdp_arrival_roofline=13.325441340699305,
+        stdp_ltp_roofline=8.622991473542916,
+        step_roofline=0.1609894183461421),
+    "trace_4x4_scoped.xplane.pb": dict(
+        device_idle_share=2.5441963097965536,
+        gather_scatter_share=97.05963447488405,
+        izhikevich_roofline=28.095680705284046,
+        stdp_arrival_roofline=13.306015252394214,
+        stdp_ltp_roofline=8.618280831749528,
+        step_roofline=0.16111453237401038),
+}
+ONE_CHIP_KINDS = {"other": 310, "gather_scatter": 14, "kernel:izhikevich": 2,
+                  "kernel:stdp_arrival": 2, "kernel:stdp_ltp": 2}
+
+
+def _record(t, n_synapses=3_200_000, n_neurons=16_000, chips=1):
+    from repro.core.params import GridConfig
+    return run.Record(setup={}, setup_s=0.0, window_s=t.window_s, steps=2,
+                      dt_ms=1.0, n_neurons=n_neurons, n_synapses=n_synapses,
+                      delay_slots=GridConfig(grid_x=4,
+                                             grid_y=4).n_delay_slots,
+                      peak_bytes=None, device_kind="TPU v5 lite",
+                      chips=chips, trace=t)
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_one_chip_trace_readings_are_pinned(name):
+    import collections
+    t = trace_reduce.reduce(os.path.join(BENCH, "testdata", name), 1)
+    rec = _record(t)
+    for metric, want in PINNED[name].items():
+        assert run.reader("per_layer", metric)(rec) == pytest.approx(
+            want, rel=1e-12), metric
+    assert run.reader("per_layer", "exchange_exposed_share")(rec) is None
+    assert collections.Counter(map(trace_reduce.kind, t.ops)) == \
+        ONE_CHIP_KINDS
+
+
 def test_union_merges_overlaps():
     assert trace_reduce._union([(5, 7), (0, 2), (1, 3), (7, 8)]) == [
         (0, 3), (5, 8)]
+
+
+def test_exposed_is_the_part_no_other_op_covers():
+    """On one device a collective from 0 to 10 under compute from 2 to 5
+    and 8 to 12 is exposed for 2 + 3; on another, wholly covered, for 0."""
+    def op(text, a, b, d):
+        return trace_reduce.Op(text=text, start_ns=a * 1e9,
+                               dur_ns=(b - a) * 1e9, device=d)
+    coll = "%cp = s32[8]{0} collective-permute-done(%cp-start)"
+    comp = "%fusion = f32[8]{0} fusion(%p), kind=kLoop"
+    t = trace_reduce.Reduction(
+        window_s=20.0, busy_s=0.0, gaps=[], n_devices=2,
+        busy_per_device=[], ops=[op(coll, 0, 10, 0), op(comp, 2, 5, 0),
+                                 op(comp, 8, 12, 0), op(coll, 1, 2, 1),
+                                 op(comp, 0, 3, 1)])
+    assert t.exposed_per_device(trace_reduce.is_collective) == {0: 5.0,
+                                                               1: 0.0}
+    assert t.exposed(trace_reduce.is_collective) == 2.5
 
 
 # ---------------------------------------------------------------------------

@@ -75,6 +75,18 @@ def test_kernels_by_name_and_by_results(path, named):
         assert by_name == (by_types if named else []), k
 
 
+def test_four_chip_collectives_are_the_exchange_scope():
+    """On four chips every collective the reduction finds lies in the
+    program's `exchange` scope."""
+    with open(os.path.join(DATA, "trace_4x4_h4.hlo.txt")) as f:
+        scope_of = scopes.of_instructions(f.read())
+    trace = trace_reduce.reduce(os.path.join(DATA, "trace_4x4_h4.xplane.pb"),
+                                4)
+    coll = [o for o in trace.ops if trace_reduce.is_collective(o)]
+    assert coll
+    assert {scope_of.get(o.name) for o in coll} == {"exchange"}
+
+
 def test_gap_names_come_from_the_span_list(scoped):
     trace, _ = scoped
     assert trace.gaps

@@ -12,8 +12,11 @@ takes from the trace:
   devices the cell uses; idle gaps: the rest of the window on the first
   device, each named by the innermost host span around its middle;
 - for each operation its HLO text, which the TPU trace gives as the
-  event's name, and from which `kind` tells the Pallas kernels and the
-  gathers and scatters apart.
+  event's name, and from which `kind` tells the collectives, the Pallas
+  kernels and the gathers and scatters apart;
+- per device, the time of operations of one kind that no other operation
+  on that device covers (`exposed`): for the collectives, the time the
+  device waits on the exchange.
 
 What the text shows (read by hand on a 4x4 trace, chip_bench/testdata):
 the Pallas kernels are `custom-call`s with
@@ -25,6 +28,16 @@ gathers and scatters on the TPU are fusions of `kind=kCustom` over an s32
 index operand (the E-wide `last_post[tgt]`, `spiked[tgt]`,
 `spiked_src[src]` and the scatter-add of `segment_sum`); the text does not
 say which of the two a fusion is.
+
+On four chips (chip_bench/testdata/trace_4x4_h4.xplane.pb, the halo wire
+under the pipelined schedule) each device plane holds the same program's
+ops, and the exchange's `ppermute`s are the HLO's async
+`collective-permute-start` and `collective-permute-done`, one pair per
+halo offset and step on each chip's `XLA Ops` line: the start launches the
+transfer (1-2 us there), the done waits for it (a few ns where the
+transfer is over), and the compute between them is what hides it.  The
+transfers themselves, with the async slices and copies, are on the
+first chip's `Async XLA Ops` line, which the reduction does not read.
 """
 from __future__ import annotations
 
@@ -60,6 +73,11 @@ class Op:
 
 
 _CALL = re.compile(r"^%\S+ = (.*?) custom-call\(")
+# an HLO collective or either half of its async form, as the opcode after
+# the result's type
+_COLLECTIVE = re.compile(r"^%\S+ = .*? (all-gather|collective-permute|"
+                         r"all-reduce|all-to-all|reduce-scatter)"
+                         r"(-start|-done)?\(")
 _CUSTOM_FUSION = re.compile(r" fusion\((.*)\), kind=kCustom")
 
 
@@ -76,7 +94,9 @@ def kernel_of(op: Op) -> Optional[str]:
 
 
 def kind(op: Op) -> str:
-    """kernel:<name>, gather_scatter or other."""
+    """collective, kernel:<name>, gather_scatter or other."""
+    if _COLLECTIVE.match(op.text):
+        return "collective"
     k = kernel_of(op)
     if k:
         return "kernel:" + k
@@ -84,6 +104,10 @@ def kind(op: Op) -> str:
     if m and "s32[" in m.group(1):
         return "gather_scatter"
     return "other"
+
+
+def is_collective(op: Op) -> bool:
+    return kind(op) == "collective"
 
 
 def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -96,6 +120,22 @@ def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return out
 
 
+def _overlap(a: List[Tuple[float, float]],
+             b: List[Tuple[float, float]]) -> float:
+    """Length of the intersection of two unions of intervals."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
 @dataclasses.dataclass
 class Reduction:
     window_s: float
@@ -103,11 +143,28 @@ class Reduction:
     ops: List[Op]                 # inside the window, all devices used
     gaps: List[Tuple[str, float]]  # idle gaps of the first device
     n_devices: int
+    busy_per_device: List[float]  # seconds, in device order
 
     def seconds(self, pred) -> float:
         """Device seconds of the operations `pred` accepts, per device."""
         return sum(o.dur_ns for o in self.ops if pred(o)) / 1e9 \
             / self.n_devices
+
+    def exposed_per_device(self, pred) -> Dict[int, float]:
+        """{device: seconds} in which an operation `pred` accepts runs on
+        that device and no other operation does."""
+        out = {}
+        for d in sorted({o.device for o in self.ops}):
+            mine = [o for o in self.ops if o.device == d]
+            sel = _union([(o.start_ns, o.end_ns) for o in mine if pred(o)])
+            rest = _union([(o.start_ns, o.end_ns) for o in mine
+                           if not pred(o)])
+            out[d] = (sum(b - a for a, b in sel) - _overlap(sel, rest)) / 1e9
+        return out
+
+    def exposed(self, pred) -> float:
+        """`exposed_per_device`, averaged over the devices used."""
+        return sum(self.exposed_per_device(pred).values()) / self.n_devices
 
     def calls(self, pred) -> float:
         """Operations `pred` accepts, per device."""
@@ -175,4 +232,6 @@ def reduce(path: str, n_devices: int) -> Reduction:
                          (a - t) / 1e9))
         t = max(t, b)
     return Reduction(window_s=(w1 - w0) / 1e9, busy_s=busy_s, ops=ops,
-                     gaps=gaps, n_devices=n_devices)
+                     gaps=gaps, n_devices=n_devices,
+                     busy_per_device=[sum(b - a for a, b in u) / 1e9
+                                      for u in busy])
